@@ -29,7 +29,7 @@ and the verification half industrialised:
 
 Replica count is the horizontal scaling knob: replicas follow
 validators, light clients fan out across replicas, and the validator
-set never sees read traffic (bench.py BENCH_ONLY=light measures the
+set never sees read traffic (tools/read_loadgen.py measures the
 one-vs-two-replica scaling).
 """
 

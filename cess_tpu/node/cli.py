@@ -15,7 +15,6 @@ purge-chain) mapped onto this framework's service:
   events        fetch one block's deposited events (chain_getEvents)
   proof         fetch + verify a Merkle state read proof (stateless:
                 the only thing trusted is the root hash)
-  bench         run the repo bench (north-star measurement)
 """
 
 from __future__ import annotations
@@ -340,13 +339,6 @@ def _cmd_proof(args) -> int:
     return 0
 
 
-def _cmd_bench(_args) -> int:
-    import runpy
-
-    runpy.run_path("bench.py", run_name="__main__")
-    return 0
-
-
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="cess_tpu", description="CESS-TPU node CLI"
@@ -482,9 +474,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="JSON-encoded map key (keyed surfaces only), "
                          'e.g. \'"alice"\'')
     pr.set_defaults(fn=_cmd_proof)
-
-    be = sub.add_parser("bench", help="run the north-star bench")
-    be.set_defaults(fn=_cmd_bench)
     return p
 
 

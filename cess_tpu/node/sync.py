@@ -421,7 +421,7 @@ def verify_justifications_batch(
     refused batch falls back to the serial verifier per structurally
     valid item, so accept/reject decisions are bit-identical to
     calling `verify_justification` one at a time — asserted in
-    tests/test_light.py and bench.py's BENCH_ONLY=light A/B.
+    tests/test_light.py.
 
     `stats`, when given, accumulates "pairings": the number of pairing
     checks evaluated (1 for an accepted batch; 1 + one per candidate

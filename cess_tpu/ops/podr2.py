@@ -233,8 +233,8 @@ def zero_data_sigmas(
 ) -> list[G1Point]:
     """σ = [sk]·Π_c H(name‖i_c)^{v_c} per name: with μ = 0 these are valid
     proofs over all-zero fragments, whose verifier-side cost is that of
-    arbitrary data (bench.py and chip_smoke.py craft their audit rounds
-    this way).  Host only — native hash-to-curve, host scalar muls — so
+    arbitrary data (the benchmark's verify cells and chip_smoke.py craft
+    their audit rounds this way).  Host only — native hash-to-curve, host scalar muls — so
     crafting compiles nothing on the device it is about to measure."""
     cnt = min(len(challenge.indices), len(challenge.randoms))
     coeffs = challenge.coefficients()[:cnt]

@@ -27,8 +27,8 @@ for — RS-reconstructing 10 GiB is half the denominator):
 * **RSStream** — chunked transfer/compute overlap: the host packs and
   `device_put`s tile t+1 while tile t's matmul runs under JAX async
   dispatch, with buffer donation on the reconstruct path (TPU).
-  bench.py, parallel/epoch_sim.py's RS stage, and the chain sim's
-  upload/recovery helpers (chain/node.py) all drive it.
+  The benchmark's repair cell, parallel/epoch_sim.py's RS stage, and
+  the chain sim's upload/recovery helpers (chain/node.py) all drive it.
 * **Mesh sharding in the core API** — `mesh=` on the batch calls
   shards the segment axis over a `jax.sharding.Mesh` via shard_map
   (embarrassingly parallel, no collectives); `RSStream.run` shards
@@ -39,10 +39,11 @@ for — RS-reconstructing 10 GiB is half the denominator):
   accept a per-segment survivor list, group segments by survivor mask
   (one host `mat_inv` per distinct mask), and run one batched matmul
   stream per group — bit-identical to the per-item numpy reference.
-* **Stage histograms** — streams observe the always-on
-  `cess_rs_{pack,matmul,dispatch_wait,unpack}_seconds` histograms
-  (rs_stage_registry, merged into node `system_metrics`), mirroring
-  the proof pipelines; docs/perf.md explains how to read the overlap.
+* **Stage histograms and spans** — streams observe the always-on
+  `cess_rs_<stage>_seconds` histograms (rs_stage_registry, merged into
+  node `system_metrics`) and open a `cess.rs.<stage>` profiler span
+  over each, mirroring the proof pipelines; docs/perf.md explains how
+  to read them.
 
 Reference behavior being re-expressed: segment -> fragment erasure
 coding with 1.5x redundancy (reference: runtime/src/lib.rs:1025,
@@ -53,7 +54,6 @@ BASELINE.json configs.
 from __future__ import annotations
 
 import os
-import threading
 import time as _time
 from functools import lru_cache, reduce
 
@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..utils.stages import StagePlane
 from . import gf256
 
 # Byte-axis tile width for streams (CESS_RS_TILE overrides).  1 MiB
@@ -88,58 +89,40 @@ COMPILE_COUNTS = {"bitplane": 0, "gather": 0}
 # ------------------------------------------------------- stage telemetry
 #
 # Always-on per-stage histograms of the streamed data plane, the RS
-# counterpart of proof/xla_backend.py's proof_stage_registry: `pack` is
-# host tile slicing + async upload, `matmul` the async kernel
-# dispatches, `dispatch_wait` the final block on device results (the
-# device time host packing failed to hide), `unpack` the device→host
-# pulls + reassembly.  The registry is process-wide and merged into
-# node `system_metrics` (node/rpc.py); CESS_STAGE_METRICS=0 switches
-# the marks off for A/B measurement, same knob as the proof stages.
+# counterpart of proof/xla_backend.py's PROOF_STAGES, each also a
+# profiler span `cess.rs.<stage>` over the same interval
+# (utils/stages.py): `pack` is a tile's or slab's host work up to its
+# upload, and holds `gather` (host copies: group gather, padded copy,
+# contiguous copy) and `put` (`jax.device_put`); `matmul` the async
+# kernel dispatches; `dispatch_wait` the final block on device results
+# (the device time host packing failed to hide); `unpack` the
+# device→host pulls and reassembly, holding `pull` (`np.asarray` of a
+# device result) and `scatter` (concatenate, slice, scatter into the
+# output).  Each slab dispatch counts its live segments and its slots.
+# The registry is process-wide and merged into node `system_metrics`
+# (node/rpc.py); CESS_STAGE_METRICS=0 switches it off for A/B
+# measurement, same knob as the proof stages.
 
-RS_STAGE_NAMES = ("pack", "matmul", "dispatch_wait", "unpack")
-STAGE_METRICS_ENABLED = os.environ.get(
-    "CESS_STAGE_METRICS", "1") not in ("0", "false", "off")
+RS_STAGE_NAMES = ("pack", "gather", "put", "matmul", "dispatch_wait",
+                  "unpack", "pull", "scatter")
 
-_rs_stage_lock = threading.Lock()
-_rs_stage_registry = None
-_rs_stage_hists: dict = {}
-_rs_stage_counters: dict = {}
-
-_RS_STAGE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
-                     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0)
+RS_STAGES = StagePlane(
+    "rs", RS_STAGE_NAMES, "cess_rs_{}_seconds", "RS stream {} stage time", {
+        "bytes": ("cess_rs_bytes_total",
+                  "payload bytes through streamed RS kernels"),
+        "streams": ("cess_rs_streams_total", "RSStream passes executed"),
+        "seconds": ("cess_rs_seconds_total",
+                    "wall-clock seconds spent in RS streams"),
+        "slab_segments": ("cess_rs_slab_segments_total",
+                          "live segments in dispatched RSStream slabs"),
+        "slab_slots": ("cess_rs_slab_slots_total",
+                       "segment slots of dispatched RSStream slabs"),
+    })
 
 
 def rs_stage_registry():
-    """The process-wide metrics registry for the RS data plane (created
-    on first use; node/metrics is imported lazily to keep the ops↔node
-    package import graph acyclic)."""
-    global _rs_stage_registry
-    with _rs_stage_lock:
-        if _rs_stage_registry is None:
-            from ..node import metrics as m
-
-            reg = m.Registry()
-            for name in RS_STAGE_NAMES:
-                _rs_stage_hists[name] = m.Histogram(
-                    f"cess_rs_{name}_seconds",
-                    f"RS stream {name} stage time",
-                    buckets=_RS_STAGE_BUCKETS, registry=reg)
-            _rs_stage_counters["bytes"] = m.Counter(
-                "cess_rs_bytes_total",
-                "payload bytes through streamed RS kernels", reg)
-            _rs_stage_counters["streams"] = m.Counter(
-                "cess_rs_streams_total",
-                "RSStream passes executed", reg)
-            _rs_stage_counters["seconds"] = m.Counter(
-                "cess_rs_seconds_total",
-                "wall-clock seconds spent in RS streams", reg)
-            _rs_stage_registry = reg
-    return _rs_stage_registry
-
-
-def _observe_rs_stage(name: str, seconds: float) -> None:
-    rs_stage_registry()
-    _rs_stage_hists[name].observe(seconds)
+    """The process-wide metrics registry for the RS data plane."""
+    return RS_STAGES.registry()
 
 
 # ------------------------------------------------- device-constant caches
@@ -565,6 +548,16 @@ class RSCode:
 # ---------------------------------------------------------------- streams
 
 
+def _host_tile(part: np.ndarray, shape: tuple[int, ...]) -> np.ndarray:
+    """`part` zero-padded at the end of each axis to `shape` (the padded
+    tail keeps one kernel shape), contiguous for `device_put`."""
+    if part.shape != shape:
+        padded = np.zeros(shape, dtype=np.uint8)
+        padded[tuple(slice(0, d) for d in part.shape)] = part
+        part = padded
+    return np.ascontiguousarray(part)
+
+
 class RSStream:
     """Streamed RS over GiB-scale host arrays with transfer/compute
     overlap.
@@ -577,16 +570,13 @@ class RSStream:
     lists for `run_batch`) streams reconstruction, with buffer
     donation on TPU (in/out shapes match on the k -> k decode).
 
-    Stage seconds land in the always-on cess_rs_* histograms and, when
-    a `stages` dict is given, accumulate there per call — `pack` vs
-    `dispatch_wait` is the overlap read, exactly as in the fused proof
-    pipeline (docs/perf.md).
+    Stage seconds land in the always-on cess_rs_* histograms, each
+    under a `cess.rs.<stage>` profiler span (RS_STAGES; docs/perf.md).
     """
 
     def __init__(
         self, code: RSCode, *, present=None, mesh=None,
         tile: int | None = None, slab: int | None = None,
-        stages: dict | None = None,
     ) -> None:
         self.code = code
         self.mesh = mesh
@@ -599,7 +589,6 @@ class RSStream:
             slab = -(-slab // n_dev) * n_dev
             self.tile = -(-self.tile // n_dev) * n_dev
         self.slab = slab
-        self.stages = stages
         self.present = present
         if present is not None and not _is_per_segment(present):
             # validate the shared mask once, up front
@@ -607,22 +596,10 @@ class RSStream:
 
     # -- telemetry ------------------------------------------------------
 
-    def _mark(self, name: str, t0: float) -> float:
-        now = _time.perf_counter()
-        if self.stages is not None:
-            self.stages[name] = self.stages.get(name, 0.0) + (now - t0)
-        if STAGE_METRICS_ENABLED:
-            _observe_rs_stage(name, now - t0)
-        return now
-
-    def _account(self, nbytes: int, t_start: float) -> None:
-        if STAGE_METRICS_ENABLED:
-            rs_stage_registry()
-            _rs_stage_counters["bytes"].inc(nbytes)
-            _rs_stage_counters["streams"].inc()
-            _rs_stage_counters["seconds"].inc(
-                _time.perf_counter() - t_start
-            )
+    @staticmethod
+    def _account(nbytes: int, t_start: float) -> None:
+        RS_STAGES.count(bytes=nbytes, streams=1,
+                        seconds=_time.perf_counter() - t_start)
 
     # -- byte-axis stream ----------------------------------------------
 
@@ -643,6 +620,7 @@ class RSStream:
         stream, asserted by COMPILE_COUNTS.
         """
         code = self.code
+        stage = RS_STAGES.stage
         t_start = _time.perf_counter()
         _check_shards(data, code.k, batched=False)
         if self.present is None and data.shape[0] != code.k:
@@ -651,33 +629,30 @@ class RSStream:
                 f"got {data.shape[0]}"
             )
         data = np.asarray(data, dtype=np.uint8)[: code.k]
-        mat = self._op_matrix()
-        mat_dev = code._mat_dev(mat)
+        mat_dev = code._mat_dev(self._op_matrix())
         n = data.shape[1]
         tile = self.tile
         donate = self.present is not None
-        t0 = t_start
         outs = []
         pulled = []
         for off in range(0, n, tile):
-            chunk = data[:, off : off + tile]
-            if chunk.shape[1] != tile:  # padded tail: one shape only
-                padded = np.zeros((code.k, tile), dtype=np.uint8)
-                padded[:, : chunk.shape[1]] = chunk
-                chunk = padded
-            dev = jax.device_put(np.ascontiguousarray(chunk))
-            t0 = self._mark("pack", t0)
-            if self.mesh is not None:
-                fn = _sharded_cols_fn(self.mesh, code.path)
-                out = (
-                    fn(mat_dev, dev)
-                    if code.path == "bitplane"
-                    else fn(mat_dev, dev, code._mul_table)
-                )
-            else:
-                out = code._kernel(mat_dev, dev, donate=donate)
-            outs.append(out)
-            t0 = self._mark("matmul", t0)
+            with stage("pack"):
+                with stage("gather"):
+                    host = _host_tile(data[:, off : off + tile],
+                                      (code.k, tile))
+                with stage("put"):
+                    dev = jax.device_put(host)
+            with stage("matmul"):
+                if self.mesh is not None:
+                    fn = _sharded_cols_fn(self.mesh, code.path)
+                    out = (
+                        fn(mat_dev, dev)
+                        if code.path == "bitplane"
+                        else fn(mat_dev, dev, code._mul_table)
+                    )
+                else:
+                    out = code._kernel(mat_dev, dev, donate=donate)
+                outs.append(out)
             if len(outs) > 1:
                 # pull tile t-1's result under tile t's compute: the
                 # device→host copy of an already-finished tile overlaps
@@ -685,16 +660,18 @@ class RSStream:
                 # behind the final drain.  The pull still counts as
                 # `unpack` — overlapped or not, it is device→host
                 # reassembly time (keeps the stage histogram honest).
-                # cesslint: allow[host-sync] pulls the PREVIOUS tile,
-                # already computed, while tile t is still in flight
-                pulled.append(np.asarray(outs[-2]))
-                outs[-2] = None
-                t0 = self._mark("unpack", t0)
-        jax.block_until_ready(outs[-1])
-        t0 = self._mark("dispatch_wait", t0)
-        pulled.append(np.asarray(outs[-1]))
-        res = np.concatenate(pulled, axis=1)[:, :n]
-        self._mark("unpack", t0)
+                with stage("unpack"), stage("pull"):
+                    # cesslint: allow[host-sync] pulls the PREVIOUS
+                    # tile, already computed, while tile t is in flight
+                    pulled.append(np.asarray(outs[-2]))
+                    outs[-2] = None
+        with stage("dispatch_wait"):
+            jax.block_until_ready(outs[-1])
+        with stage("unpack"):
+            with stage("pull"):
+                pulled.append(np.asarray(outs[-1]))
+            with stage("scatter"):
+                res = np.concatenate(pulled, axis=1)[:, :n]
         self._account(data.nbytes, t_start)
         return res
 
@@ -715,48 +692,52 @@ class RSStream:
         return pats
 
     def _stream_slabs(self, mat: np.ndarray, batch: np.ndarray, out, idx):
-        """Gather one group's segments out of `batch`, dispatch them in
-        fixed-size slabs, and scatter results into `out` rows `idx`."""
+        """Gather one group's segments out of `batch` (`idx`: the
+        group's row indices, or slice(None) for every row), dispatch
+        them in fixed-size slabs, and scatter results into `out` rows
+        `idx`."""
         code = self.code
+        stage = RS_STAGES.stage
         mat_dev = code._mat_dev(mat)
         slab = self.slab
-        t0 = _time.perf_counter()
-        batch = batch[idx, : code.k]  # group gather = host pack work
-        b = batch.shape[0]
+        b = batch.shape[0] if isinstance(idx, slice) else len(idx)
+        group = None
         outs = []
         pulled = []
         for off in range(0, b, slab):
-            chunk = batch[off : off + slab]
-            if chunk.shape[0] != slab:  # padded tail slab: one shape
-                padded = np.zeros(
-                    (slab,) + chunk.shape[1:], dtype=np.uint8
+            with stage("pack"):
+                with stage("gather"):
+                    if group is None:
+                        group = batch[idx, : code.k]  # group gather
+                    host = _host_tile(group[off : off + slab],
+                                      (slab,) + group.shape[1:])
+                with stage("put"):
+                    dev = jax.device_put(host)
+            with stage("matmul"):
+                outs.append(
+                    code._batch_kernel(
+                        mat_dev, dev, mesh=self.mesh,
+                        donate=self.present is not None,
+                    )
                 )
-                padded[: chunk.shape[0]] = chunk
-                chunk = padded
-            dev = jax.device_put(np.ascontiguousarray(chunk))
-            t0 = self._mark("pack", t0)
-            outs.append(
-                code._batch_kernel(
-                    mat_dev, dev, mesh=self.mesh,
-                    donate=self.present is not None,
-                )
-            )
-            t0 = self._mark("matmul", t0)
+                RS_STAGES.count(slab_segments=min(slab, b - off),
+                                slab_slots=slab)
             if len(outs) > 1:
                 # pull slab t-1's result under slab t's compute (see
                 # RSStream.run): overlapped device→host copies still
                 # accrue to `unpack`
-                # cesslint: allow[host-sync] pulls the PREVIOUS slab,
-                # already computed, while slab t is still in flight
-                pulled.append(np.asarray(outs[-2]))
-                outs[-2] = None
-                t0 = self._mark("unpack", t0)
-        jax.block_until_ready(outs[-1])
-        t0 = self._mark("dispatch_wait", t0)
-        pulled.append(np.asarray(outs[-1]))
-        got = np.concatenate(pulled, axis=0)[:b]
-        out[idx] = got
-        self._mark("unpack", t0)
+                with stage("unpack"), stage("pull"):
+                    # cesslint: allow[host-sync] pulls the PREVIOUS
+                    # slab, already computed, while slab t is in flight
+                    pulled.append(np.asarray(outs[-2]))
+                    outs[-2] = None
+        with stage("dispatch_wait"):
+            jax.block_until_ready(outs[-1])
+        with stage("unpack"):
+            with stage("pull"):
+                pulled.append(np.asarray(outs[-1]))
+            with stage("scatter"):
+                out[idx] = np.concatenate(pulled, axis=0)[:b]
 
     def run_batch(self, batch: np.ndarray) -> np.ndarray:
         """(B, rows, n) host segments -> (B, out_rows, n) host uint8.

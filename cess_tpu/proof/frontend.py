@@ -19,22 +19,68 @@ staged XlaBackend path:
   * ρ weights pack to 12-bit MSM digits and 7-bit fr limbs through the
     word-level codecs in ops/fr.py instead of per-limb loops.
 
+`front_end` is the serial part both pipelines run first (pk and σ
+decoding, the encode pass, the μ range check, the ρ transcript); a
+batch it rejects raises `Rejected`.
+
 Everything here is bit-identical to the scalar forms it replaces —
 asserted in tests/test_proof_hotpath.py (the `proof_hotpath` CI gate).
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+
 import numpy as np
 
 from ..ops import bls12_381 as bls
-from ..ops import fr, g1
-from ..ops.bls12_381 import R
+from ..ops import fr, g1, podr2
+from ..ops.bls12_381 import G2Point, R
 
 MU_BYTES = 32
 
 # little-endian uint32 words of r, for the vectorised range compare
 _R_WORDS = np.frombuffer(R.to_bytes(MU_BYTES, "little"), dtype="<u4").copy()
+
+
+class Rejected(Exception):
+    """The batch fails a check that needs no pairing (undecodable pk or
+    σ, wrong μ width, μ out of range): its combined check is False."""
+
+
+@dataclass
+class FrontEnd:
+    pk_point: G2Point
+    sigmas: list
+    mu_words: np.ndarray  # (B, s, 8) uint32
+    rhos: list[int]
+
+
+def front_end(pk: bytes, items, seed: bytes, params) -> FrontEnd:
+    """Decode and validate a batch and derive its ρ weights from ONE
+    encode pass; raises Rejected where ops/podr2.py batch_verify would
+    return False before any group work."""
+    try:
+        pk_point = G2Point.from_bytes(pk)
+    except ValueError:
+        raise Rejected("pk") from None
+    sigmas = decompress_sigmas(items)
+    if sigmas is None:
+        raise Rejected("sigma")
+    if any(len(p.mu) != params.s for _, _, p in items):
+        raise Rejected("mu width")
+    encs = encode_proofs(items)
+    if encs is None:
+        raise Rejected("mu encoding")
+    words = mu_words(encs, params.s)
+    if not mu_in_range(words):
+        raise Rejected("mu range")
+    batch_items = [podr2.BatchItem(n, c, p) for n, c, p in items]
+    rhos = podr2.batch_rho(
+        podr2.batch_transcript(seed, batch_items, encodings=encs),
+        len(items),
+    )
+    return FrontEnd(pk_point, sigmas, words, rhos)
 
 
 def decompress_sigmas(items) -> list | None:

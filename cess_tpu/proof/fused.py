@@ -26,7 +26,7 @@ chunks run a REAL double buffer: a one-worker prefetch pool packs chunk
 k+1's inputs while chunk k's program executes under JAX async dispatch,
 with nothing blocking on device values until every chunk is in flight
 (the double-buffering called for by SURVEY.md §7 hard part 5; the
-`dispatch_wait` stage histogram is the un-hidden device remainder —
+stage spans and histograms say where a chunk's time goes —
 docs/perf.md).  With _one_shape() active every chunk pads to CHUNK
 proofs so `_verify_chunk_device` compiles exactly once per process,
 counted by COMPILE_COUNTS.
@@ -57,7 +57,7 @@ import numpy as np
 
 from ..ops import bls12_381 as bls
 from ..ops import fr, g1, glv, h2c, podr2
-from ..ops.bls12_381 import G1Point, G2Point
+from ..ops.bls12_381 import G1Point
 from ..ops.podr2 import Podr2Params
 from . import frontend
 
@@ -351,7 +351,6 @@ def combined_check_fused(
     items: list,
     seed: bytes,
     params: Podr2Params,
-    stages: dict | None = None,
 ) -> bool:
     """Bit-identical replacement for the stage-by-stage combined check.
 
@@ -367,133 +366,111 @@ def combined_check_fused(
     word-level ρ packing.  Chunks run through a double-buffered
     pipeline: chunk k's device program is dispatched asynchronously
     while a prefetch worker packs chunk k+1's host inputs, and nothing
-    blocks on device values until every chunk is in flight — the
-    `dispatch_wait` stage below is exactly the device time the host
-    prep failed to hide.
+    blocks on device values until every chunk is in flight.
 
-    Telemetry mirrors the staged path (same histogram names +
-    dispatch_wait; cess_proof_* counters), and `stages` accumulates the
-    per-call breakdown when the backend profiles."""
+    Stages (histograms and `cess.proof.*` spans, utils/stages.py):
+    `front_end` inside the first `host_prep`, which runs until chunk 0
+    is packed; later `host_prep`s are the waits for the worker; `prep`
+    is one chunk's packing on the worker; `launch` each chunk's upload
+    and dispatch, and the partial reduction's; `dispatch_wait` the
+    block on the device after that; then `u_fold` and `pairing`.  Each
+    launch counts the chunk's live proofs and padded slots."""
     if not items:
         return True
-    from .xla_backend import (
-        STAGE_METRICS_ENABLED,
-        _observe_stage,
-        _stage_counters,
-        proof_stage_registry,
-    )
+    from .xla_backend import PROOF_STAGES
 
-    metered = STAGE_METRICS_ENABLED
-
-    def mark(name, t0):
-        if not metered and stages is None:
-            return t0
-        now = _time.perf_counter()
-        if stages is not None:
-            stages[name] = stages.get(name, 0.0) + (now - t0)
-        if metered:
-            _observe_stage(name, now - t0)
-        return now
-
+    stage = PROOF_STAGES.stage
     check_t0 = _time.perf_counter()
-    t0 = check_t0
     try:
-        pk_point = G2Point.from_bytes(pk)
-    except ValueError:
-        return False
-    sigmas = frontend.decompress_sigmas(items)
-    if sigmas is None:
-        return False
-    if any(len(p.mu) != params.s for _, _, p in items):
-        return False
-    encs = frontend.encode_proofs(items)
-    if encs is None:
-        return False
-    mu_w = frontend.mu_words(encs, params.s)
-    if not frontend.mu_in_range(mu_w):
-        return False
-    batch_items = [podr2.BatchItem(n, c, p) for n, c, p in items]
-    rhos = podr2.batch_rho(
-        podr2.batch_transcript(seed, batch_items, encodings=encs),
-        len(items),
-    )
+        with stage("host_prep"):
+            with stage("front_end"):
+                fe = frontend.front_end(pk, items, seed, params)
 
-    # one program shape per call: every chunk shares (Bp, npad, g) —
-    # and with _one_shape() they are process-constant for a given
-    # challenge geometry, so _verify_chunk_device compiles once ever.
-    chunk = CHUNK
-    counts_all = [
-        min(len(ch.indices), len(ch.randoms)) for _, ch, _ in items
-    ]
-    g, tile, pad_lanes = _geometry(max(counts_all))
-    if _one_shape():
-        pad_b = chunk
-    else:
-        pad_b = pad_lanes = None  # per-chunk pow2 / exact tiling
+            # one program shape per call: every chunk shares (Bp, npad,
+            # g) — and with _one_shape() they are process-constant for a
+            # given challenge geometry, so _verify_chunk_device compiles
+            # once ever.
+            chunk = CHUNK
+            counts_all = [
+                min(len(ch.indices), len(ch.randoms)) for _, ch, _ in items
+            ]
+            g, tile, pad_lanes = _geometry(max(counts_all))
+            if _one_shape():
+                pad_b = chunk
+            else:
+                pad_b = pad_lanes = None  # per-chunk pow2 / exact tiling
+            spans = list(range(0, len(items), chunk))
 
-    spans = list(range(0, len(items), chunk))
+            def prep(start):
+                with stage("prep"):
+                    return _prep_chunk(
+                        items[start : start + chunk],
+                        fe.sigmas[start : start + chunk],
+                        fe.rhos[start : start + chunk],
+                        fe.mu_words[start : start + chunk],
+                        counts_all[start : start + chunk],
+                        params, pad_b, pad_lanes, g, tile,
+                    )
 
-    def prep(start):
-        return _prep_chunk(
-            items[start : start + chunk],
-            sigmas[start : start + chunk],
-            rhos[start : start + chunk],
-            mu_w[start : start + chunk],
-            counts_all[start : start + chunk],
-            params, pad_b, pad_lanes, g, tile,
-        )
+            pool = _prep_pool()
+            fut = pool.submit(prep, spans[0])
+            host_in = fut.result()
+    except frontend.Rejected:
+        return False
 
     outs: list[_ChunkOut] = []
-    pool = _prep_pool()
-    fut = pool.submit(prep, spans[0])
-    for si in range(len(spans)):
-        host_in = fut.result()
-        t0 = mark("host_prep", t0)
-        if si + 1 < len(spans):
-            fut = pool.submit(prep, spans[si + 1])
-        outs.append(_launch_chunk(host_in))  # async device dispatch
-        t0 = mark("chunk_program", t0)
+    for si, start in enumerate(spans):
+        if si:
+            with stage("host_prep"):
+                host_in = fut.result()
+        with stage("launch"):
+            if si + 1 < len(spans):
+                fut = pool.submit(prep, spans[si + 1])
+            outs.append(_launch_chunk(host_in))  # async device dispatch
+            # host_in[-1] is mu_words, one row per proof slot
+            PROOF_STAGES.count(chunk_proofs=min(chunk, len(items) - start),
+                               chunk_slots=len(host_in[-1]))
 
     # one device reduction over the chunk partials, one host pull
-    lhs = _accumulate_points(
-        jnp.stack([o.lhs[0] for o in outs], axis=-1),
-        jnp.stack([o.lhs[1] for o in outs], axis=-1),
-        jnp.stack([o.lhs[2] for o in outs], axis=-1),
-    )
-    rhs = _accumulate_points(
-        jnp.stack([o.rhs[0] for o in outs], axis=-1),
-        jnp.stack([o.rhs[1] for o in outs], axis=-1),
-        jnp.stack([o.rhs[2] for o in outs], axis=-1),
-    )
-    exps = _finalize_exps(jnp.stack([o.exps for o in outs]))
-    masks = jnp.concatenate([o.mask for o in outs])
-    t0 = mark("chunk_program", t0)
-    jax.block_until_ready((lhs, rhs, exps, masks))
-    t0 = mark("dispatch_wait", t0)
-
-    if not bool(np.all(np.asarray(masks) == 1)):
-        verdict = False
-    else:
-        lhs_pt = g1.projective_to_points(
-            *(np.asarray(a).reshape(1, -1) for a in lhs)
-        )[0]
-        rhs_pt = g1.projective_to_points(
-            *(np.asarray(a).reshape(1, -1) for a in rhs)
-        )[0]
-        exps_ints = fr.limbs_to_ints(np.asarray(exps))
-
-        us = list(podr2.u_generators(params.s))
-        rhs_pt = rhs_pt + g1.msm(us, exps_ints)
-        t0 = mark("u_fold", t0)
-        verdict = bls.pairing_check(
-            [(lhs_pt, -bls.G2_GENERATOR), (rhs_pt, pk_point)]
+    with stage("launch"):
+        lhs = _accumulate_points(
+            jnp.stack([o.lhs[0] for o in outs], axis=-1),
+            jnp.stack([o.lhs[1] for o in outs], axis=-1),
+            jnp.stack([o.lhs[2] for o in outs], axis=-1),
         )
-        mark("pairing", t0)
-    if metered:
-        proof_stage_registry()
-        _stage_counters["checks"].inc()
-        _stage_counters["proofs"].inc(len(items))
-        _stage_counters["seconds"].inc(_time.perf_counter() - check_t0)
+        rhs = _accumulate_points(
+            jnp.stack([o.rhs[0] for o in outs], axis=-1),
+            jnp.stack([o.rhs[1] for o in outs], axis=-1),
+            jnp.stack([o.rhs[2] for o in outs], axis=-1),
+        )
+        exps = _finalize_exps(jnp.stack([o.exps for o in outs]))
+        masks = jnp.concatenate([o.mask for o in outs])
+    with stage("dispatch_wait"):
+        jax.block_until_ready((lhs, rhs, exps, masks))
+
+    # a σ outside the subgroup raises out of u_fold, which then
+    # observes nothing; the check still counts
+    try:
+        with stage("u_fold"):
+            if not bool(np.all(np.asarray(masks) == 1)):
+                raise frontend.Rejected("sigma subgroup")
+            lhs_pt = g1.projective_to_points(
+                *(np.asarray(a).reshape(1, -1) for a in lhs)
+            )[0]
+            rhs_pt = g1.projective_to_points(
+                *(np.asarray(a).reshape(1, -1) for a in rhs)
+            )[0]
+            exps_ints = fr.limbs_to_ints(np.asarray(exps))
+            us = list(podr2.u_generators(params.s))
+            rhs_pt = rhs_pt + g1.msm(us, exps_ints)
+        with stage("pairing"):
+            verdict = bls.pairing_check(
+                [(lhs_pt, -bls.G2_GENERATOR), (rhs_pt, fe.pk_point)]
+            )
+    except frontend.Rejected:
+        verdict = False
+    PROOF_STAGES.count(checks=1, proofs=len(items),
+                       seconds=_time.perf_counter() - check_t0)
     return verdict
 
 

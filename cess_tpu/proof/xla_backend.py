@@ -25,7 +25,6 @@ declared verification seam (c-pallets/audit/src/lib.rs:484).
 from __future__ import annotations
 
 import os
-import threading
 import time as _time
 
 import jax
@@ -33,8 +32,9 @@ import numpy as np
 
 from ..ops import bls12_381 as bls
 from ..ops import fr, g1, h2c, podr2
-from ..ops.bls12_381 import G1Point, G2Point
+from ..ops.bls12_381 import G1Point
 from ..ops.podr2 import Challenge, Podr2Params, Podr2Proof
+from ..utils.stages import StagePlane
 from .backend import ProofBackend, ProveRequest, VerifyItem
 
 # Fragment-axis chunk for prove_batch: bounds host staging + HBM footprint
@@ -58,76 +58,53 @@ _DEVICE_H2C_MIN_PAIRS = 256
 
 # ------------------------------------------------------- stage telemetry
 #
-# Always-on per-stage histograms of _combined_check (the promotion of
-# the opt-in profile_stages breakdown — ROADMAP item 1 needs per-stage
-# timing that survives outside bench.py).  They live in a process-wide
-# registry of their own so any host embedding a backend (node RPC,
-# TEE client, bench) exposes them without threading a registry through
-# the proof API; the node's `system_metrics` merges this registry into
-# its exposition (node/rpc.py).
+# Always-on per-stage histograms of the combined check, in a
+# process-wide registry of their own so any host embedding a backend
+# (node RPC, TEE client, the benchmark) exposes them without threading
+# a registry through the proof API; the node's `system_metrics` merges
+# this registry into its exposition (node/rpc.py).  Each stage is also
+# a profiler span `cess.proof.<stage>` over the same interval
+# (utils/stages.py), so a device trace names the stage the host was in.
 #
-# BOTH verify pipelines observe the same stage names: the staged path
-# below marks host_prep/u_fold/sigma_fold/chunk_program/pairing, and
-# the fused single-program path (proof/fused.py combined_check_fused)
-# marks host_prep/chunk_program/u_fold/pairing plus `dispatch_wait` —
-# the block on device results after every chunk is in flight, i.e. the
-# device time the double-buffered host prep failed to hide (σ work is
-# inside the fused chunk program, so sigma_fold has no fused
-# observations).  docs/perf.md explains how to read the split.
+# BOTH verify pipelines mark `front_end` (decode, encode pass, ρ
+# transcript; nested in the first `host_prep`) and `host_prep`,
+# `u_fold`, `pairing`.  The staged path below adds sigma_fold and
+# chunk_program (the H-side folds, real work); the fused single-program
+# path (proof/fused.py combined_check_fused) adds `prep` (one chunk's
+# packing, on the prefetch worker), `launch` (upload + asynchronous
+# dispatch of each chunk, and of the partial reduction) and
+# `dispatch_wait` (the block on device results after every chunk is in
+# flight).  The fused path also counts each launched chunk's live
+# proofs and padded slots.  docs/perf.md explains how to read the split.
 #
-# Overhead guard: each stage below already ends in a host
-# materialization, so a mark is ONE perf_counter call plus one locked
-# histogram observe — single-digit microseconds against stages that
-# cost milliseconds.  tests/test_telemetry.py measures the mark cost,
-# and bench.py's marginal ms/proof is the end-to-end check (< 2%
-# budget).  CESS_STAGE_METRICS=0 switches the marks off entirely for
+# Overhead guard: each stage costs two perf_counter calls, one span
+# object and one locked histogram observe — microseconds against
+# stages that cost milliseconds (tests/test_telemetry.py measures it).
+# CESS_STAGE_METRICS=0 switches spans, histograms and counters off for
 # A/B measurement.
 
-STAGE_NAMES = ("host_prep", "u_fold", "sigma_fold", "chunk_program",
-               "dispatch_wait", "pairing")
-STAGE_METRICS_ENABLED = os.environ.get(
-    "CESS_STAGE_METRICS", "1") not in ("0", "false", "off")
+STAGE_NAMES = ("front_end", "host_prep", "prep", "launch", "u_fold",
+               "sigma_fold", "chunk_program", "dispatch_wait", "pairing")
 
-_stage_lock = threading.Lock()
-_stage_registry = None
-_stage_hists: dict = {}
-_stage_counters: dict = {}
-
-_STAGE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
-                  0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0)
+PROOF_STAGES = StagePlane(
+    "proof", STAGE_NAMES, "cess_proof_stage_{}_seconds",
+    "combined-check {} stage time", {
+        "proofs": ("cess_proofs_verified",
+                   "proof items covered by combined checks"),
+        "checks": ("cess_proof_checks",
+                   "combined pairing checks executed"),
+        "seconds": ("cess_proof_verify_seconds_total",
+                    "wall-clock seconds spent in combined checks"),
+        "chunk_proofs": ("cess_proof_chunk_proofs_total",
+                         "live proofs in launched fused chunks"),
+        "chunk_slots": ("cess_proof_chunk_slots_total",
+                        "padded proof slots of launched fused chunks"),
+    })
 
 
 def proof_stage_registry():
-    """The process-wide metrics registry for the proof data plane
-    (created on first use; node/metrics is imported lazily to keep the
-    proof↔node package import graph acyclic)."""
-    global _stage_registry
-    with _stage_lock:
-        if _stage_registry is None:
-            from ..node import metrics as m
-
-            reg = m.Registry()
-            for name in STAGE_NAMES:
-                _stage_hists[name] = m.Histogram(
-                    f"cess_proof_stage_{name}_seconds",
-                    f"combined-check {name} stage time",
-                    buckets=_STAGE_BUCKETS, registry=reg)
-            _stage_counters["proofs"] = m.Counter(
-                "cess_proofs_verified",
-                "proof items covered by combined checks", reg)
-            _stage_counters["checks"] = m.Counter(
-                "cess_proof_checks",
-                "combined pairing checks executed", reg)
-            _stage_counters["seconds"] = m.Counter(
-                "cess_proof_verify_seconds_total",
-                "wall-clock seconds spent in combined checks", reg)
-            _stage_registry = reg
-    return _stage_registry
-
-
-def _observe_stage(name: str, seconds: float) -> None:
-    proof_stage_registry()
-    _stage_hists[name].observe(seconds)
+    """The process-wide metrics registry for the proof data plane."""
+    return PROOF_STAGES.registry()
 
 
 def _subgroup_ok(points, device: bool | None = None) -> bool:
@@ -186,17 +163,8 @@ class XlaBackend(ProofBackend):
         mesh=None,
         device_h2c: bool | None = None,
         fused: bool | None = None,
-        profile_stages: bool = False,
     ) -> None:
         self.mesh = mesh
-        # profile_stages: accumulate a per-stage wall-clock breakdown of
-        # _combined_check into `stage_seconds` (host prep / u fold /
-        # σ fold / chunk program / pairing).  Each boundary blocks on
-        # the stage's device values so the split is real — run it on a
-        # SEPARATE pass, never the timed one (bench.py does exactly
-        # that; the blocking serializes the dispatch pipeline).
-        self.profile_stages = profile_stages
-        self.stage_seconds: dict[str, float] = {}
         # fused: None = auto (the single-program GLV pipeline of
         # proof/fused.py on a real TPU); True/False force it — tests
         # force True to exercise the fused path on the CPU mesh.
@@ -333,108 +301,87 @@ class XlaBackend(ProofBackend):
         if self._use_fused():
             from .fused import combined_check_fused
 
-            return combined_check_fused(
-                pk, items, seed, params,
-                stages=self.stage_seconds if self.profile_stages else None,
-            )
+            return combined_check_fused(pk, items, seed, params)
         from . import frontend
 
-        stages = self.stage_seconds if self.profile_stages else None
-        metered = STAGE_METRICS_ENABLED
-
-        def mark(name, t0):
-            """Stage boundary: charge the elapsed wall clock to `name`.
-            Honest because every stage below ends in host
-            materialization (g1.msm / limbs_to_ints return host
-            values, pairing_check is host) — a stage changed to return
-            a device-resident array must add its own block_until_ready
-            here or its cost silently migrates to the next bucket.
-            Always on: the per-stage histograms (proof_stage_registry)
-            observe every combined check; `profile_stages` additionally
-            accumulates the per-backend stage_seconds dict bench.py
-            logs.  One perf_counter + one locked observe per stage —
-            the measured-overhead guard in tests/test_telemetry.py."""
-            if not metered and stages is None:
-                return t0
-            now = _time.perf_counter()
-            if stages is not None:
-                stages[name] = stages.get(name, 0.0) + (now - t0)
-            if metered:
-                _observe_stage(name, now - t0)
-            return now
-
-        # The whole front-end sits AFTER check_t0 so host_prep means
-        # the same thing on both pipelines (the fused path charges its
-        # front-end to host_prep too — bench/profile breakdowns are
-        # compared side by side).  Early rejections return before any
-        # mark, exactly like the fused path's.
+        # Every stage below ends in host materialization (g1.msm /
+        # limbs_to_ints return host values, pairing_check is host), so
+        # each stage's interval is its real cost — a stage changed to
+        # return a device-resident array must block on it inside its
+        # block, or the cost migrates to the next stage.  A rejected
+        # front end raises and observes nothing.
+        stage = PROOF_STAGES.stage
         check_t0 = _time.perf_counter()
-        t0 = check_t0
         try:
-            pk_point = G2Point.from_bytes(pk)
-        except ValueError:
+            with stage("host_prep"):
+                # batched σ decompression with the subgroup test
+                # deferred: the per-σ host ladder (~3 ms each) becomes
+                # ONE device [r]-chain over the whole batch below — same
+                # rejection set (tests/test_proof_backends.py
+                # non-subgroup/tampered matrix).
+                with stage("front_end"):
+                    fe = frontend.front_end(pk, items, seed, params)
+                # μ limbs come from the SAME encode pass as the
+                # transcript — a numpy word unpack instead of B·S
+                # per-limb Python loops.
+                mu_limbs = frontend.mu_limbs(fe.mu_words)  # (B, S, 37)
+        except frontend.Rejected:
             return False
-        # batched decompression with the subgroup test deferred: the
-        # per-σ host ladder (~3 ms each) becomes ONE device [r]-chain
-        # over the whole batch below — same rejection set
-        # (tests/test_proof_backends.py non-subgroup/tampered matrix).
-        sigmas = frontend.decompress_sigmas(items)
-        if sigmas is None:
-            return False
-        if any(len(p.mu) != params.s for _, _, p in items):
-            return False
-        encs = frontend.encode_proofs(items)
-        if encs is None:
-            return False
-        words = frontend.mu_words(encs, params.s)
-        if not frontend.mu_in_range(words):
-            return False
-        batch_items = [podr2.BatchItem(n, c, p) for n, c, p in items]
-        rhos = podr2.batch_rho(
-            podr2.batch_transcript(seed, batch_items, encodings=encs),
-            len(items),
-        )
-        # μ limbs come from the SAME encode pass as the transcript — a
-        # numpy word unpack instead of B·S per-limb Python loops.
-        mu_limbs = frontend.mu_limbs(words)  # (B, S, 37)
-        t0 = mark("host_prep", t0)
+        rhos = fe.rhos
 
         # σ subgroup gate: the test deferred from decompression runs as
         # one batched device [r]-chain (ops/glv.py subgroup_mask —
         # bit-identical to the host in_subgroup ladder, tests/test_fused
         # TestGlv), ∞-padded to a power of two ([r]∞ = ∞ passes).
-        sub_ok = _subgroup_ok(sigmas)
-        t0 = mark("sigma_fold", t0)
+        with stage("sigma_fold"):
+            sub_ok = _subgroup_ok(fe.sigmas)
         if not sub_ok:
             return False
 
         # u-side exponents Σ_b ρ_b μ_bj: device limb matmul (ops/fr.py) —
         # sharded over the mesh when one is configured (ρ=0 row padding
         # contributes nothing to the combination).
-        if self.mesh is not None:
-            from ..parallel import combine_mu_sharded, pad_batch_rows
+        with stage("u_fold"):
+            if self.mesh is not None:
+                from ..parallel import combine_mu_sharded, pad_batch_rows
 
-            n_dev = self.mesh.devices.size
-            rho_limbs = pad_batch_rows(
-                frontend.rho_limbs7(rhos), n_dev
-            )
-            mu_limbs = pad_batch_rows(mu_limbs, n_dev)
-            exps = fr.limbs_to_ints(
-                combine_mu_sharded(self.mesh, rho_limbs, mu_limbs)
-            )
-        else:
-            exps = fr.limbs_to_ints(fr.combine_mu(rhos, mu_limbs))
-        t0 = mark("u_fold", t0)
+                n_dev = self.mesh.devices.size
+                rho_limbs = pad_batch_rows(
+                    frontend.rho_limbs7(rhos), n_dev
+                )
+                mu_limbs = pad_batch_rows(mu_limbs, n_dev)
+                exps = fr.limbs_to_ints(
+                    combine_mu_sharded(self.mesh, rho_limbs, mu_limbs)
+                )
+            else:
+                exps = fr.limbs_to_ints(fr.combine_mu(rhos, mu_limbs))
 
         # σ-side: Π σ_b^{ρ_b} — one flat MSM over the batch.
-        lhs = g1.msm(sigmas, rhos, bits=_RHO_BITS)
-        t0 = mark("sigma_fold", t0)
+        with stage("sigma_fold"):
+            lhs = g1.msm(fe.sigmas, rhos, bits=_RHO_BITS)
 
-        # H-side: per-item Π_c H^{v_c} (grouped MSM over the challenged
-        # chunk points), then the ρ fold across items.  At batch scale
-        # the random-oracle points are hashed ON DEVICE (ops/h2c.py:
-        # host XMD → device SSWU) and stay device-resident into the MSM,
-        # with the effective cofactor folded into the coefficients.
+        with stage("chunk_program"):
+            rhs = g1.msm(self._h_inner_fold(items), rhos, bits=_RHO_BITS)
+
+        # u-side: Π_j u_j^{e_j} over the global sector generators.
+        with stage("u_fold"):
+            us = list(podr2.u_generators(params.s))
+            rhs = rhs + g1.msm(us, exps)
+
+        with stage("pairing"):
+            verdict = bls.pairing_check(
+                [(lhs, -bls.G2_GENERATOR), (rhs, fe.pk_point)]
+            )
+        PROOF_STAGES.count(checks=1, proofs=len(items),
+                           seconds=_time.perf_counter() - check_t0)
+        return verdict
+
+    def _h_inner_fold(self, items: list[VerifyItem]) -> list[G1Point]:
+        """H-side: per-item Π_c H^{v_c} (grouped MSM over the challenged
+        chunk points).  At batch scale the random-oracle points are
+        hashed ON DEVICE (ops/h2c.py: host XMD → device SSWU) and stay
+        device-resident into the MSM, with the effective cofactor folded
+        into the coefficients."""
         n_pairs = sum(len(ch.indices) for _, ch, _ in items)
         use_device = (
             self.device_h2c
@@ -442,48 +389,28 @@ class XlaBackend(ProofBackend):
             else jax.default_backend() == "tpu"
         )
         if use_device and n_pairs >= _DEVICE_H2C_MIN_PAIRS:
-            inner = self._h_inner_fold_device(items)
-        else:
-            # same zip-truncation semantics as the host reference and
-            # the device branch above
-            counts = [
-                min(len(ch.indices), len(ch.randoms)) for _, ch, _ in items
-            ]
-            flat_pairs = [
-                (name, i)
-                for (name, ch, _), c in zip(items, counts)
-                for i in ch.indices[:c]
-            ]
-            flat_pts = self._chunk_points(flat_pairs)
-            h_pts = []
-            pos = 0
-            for c in counts:
-                h_pts.append(flat_pts[pos : pos + c])
-                pos += c
-            h_coeffs = [
-                list(ch.coefficients()[:c])
-                for (_, ch, _), c in zip(items, counts)
-            ]
-            inner = g1.msm_grouped(h_pts, h_coeffs, bits=_COEFF_BITS)
-        rhs = g1.msm(inner, rhos, bits=_RHO_BITS)
-        t0 = mark("chunk_program", t0)
-
-        # u-side: Π_j u_j^{e_j} over the global sector generators.
-        us = list(podr2.u_generators(params.s))
-        rhs = rhs + g1.msm(us, exps)
-        t0 = mark("u_fold", t0)
-
-        verdict = bls.pairing_check(
-            [(lhs, -bls.G2_GENERATOR), (rhs, pk_point)]
-        )
-        mark("pairing", t0)
-        if metered:
-            proof_stage_registry()
-            _stage_counters["checks"].inc()
-            _stage_counters["proofs"].inc(len(items))
-            _stage_counters["seconds"].inc(
-                _time.perf_counter() - check_t0)
-        return verdict
+            return self._h_inner_fold_device(items)
+        # same zip-truncation semantics as the host reference and the
+        # device branch
+        counts = [
+            min(len(ch.indices), len(ch.randoms)) for _, ch, _ in items
+        ]
+        flat_pairs = [
+            (name, i)
+            for (name, ch, _), c in zip(items, counts)
+            for i in ch.indices[:c]
+        ]
+        flat_pts = self._chunk_points(flat_pairs)
+        h_pts = []
+        pos = 0
+        for c in counts:
+            h_pts.append(flat_pts[pos : pos + c])
+            pos += c
+        h_coeffs = [
+            list(ch.coefficients()[:c])
+            for (_, ch, _), c in zip(items, counts)
+        ]
+        return g1.msm_grouped(h_pts, h_coeffs, bits=_COEFF_BITS)
 
     def verify_batch(
         self,

@@ -2,8 +2,8 @@
 
 The fused verify program and the Pallas kernels take minutes to compile
 cold (proof/fused.py), so every entry point that compiles them — the
-test suite, bench.py, __graft_entry__.py, chip_smoke.py — shares one
-cache.  JAX reads `JAX_COMPILATION_CACHE_DIR` itself: when it is set,
+test suite, the benchmark, __graft_entry__.py, chip_smoke.py — shares
+one cache.  JAX reads `JAX_COMPILATION_CACHE_DIR` itself: when it is set,
 nothing here touches the directory.  Otherwise the cache lives at
 `<checkout>/.jax_cache` (gitignored), a path that never depends on a
 temp name, a pid or the clock, so a later run finds what an earlier one

@@ -9,7 +9,7 @@ call, with every input generated from `--seed`:
              go on unless JAX's first device is a TPU.
   verify     one audit round: >= 256 proofs at protocol geometry
              (1024-chunk x 265-sector fragments, 47 challenged chunks,
-             distinct fragment names), crafted as bench.py crafts them,
+             distinct fragment names), crafted as the benchmark crafts them,
              with planted forgeries, verified through
              proof.get_backend("xla") in its auto mode (the fused Pallas
              pipeline on a TPU), whose warm_up compiles the fused
@@ -48,7 +48,7 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
 
-# Protocol geometry of the verify phase (bench.py bench_verify).
+# Protocol geometry of the verify phase (perfbench/configs/tee_verifier.json).
 N_PROOFS = 256  # one fused CHUNK pads to 1024 lanes; >= 256 proofs
 N_FORGED = 2
 N_CHALLENGED = 47  # c-pallets/audit/src/lib.rs:906 — 47 of 1024 chunks
@@ -224,8 +224,8 @@ def preflight(chips: int) -> dict:
 def audit_round(seed: int, n_proofs: int, n_forged: int,
                 n_challenged: int, params, *, mu_sectors: int = 0):
     """(pk, items, truth, round seed, forged): one audit round's proofs
-    under one challenge, valid proofs crafted as bench.py bench_verify
-    crafts them (podr2.zero_data_sigmas: σ = Π H^{sk·v_c}, μ = 0), with
+    under one challenge, valid proofs crafted as the benchmark's verify
+    driver crafts them (podr2.zero_data_sigmas: σ = Π H^{sk·v_c}, μ = 0), with
     `n_forged` forgeries planted at a seeded position — alternately
     another fragment's σ and a μ that does not match the tags.  With
     `mu_sectors`, every honest proof instead carries that many seeded

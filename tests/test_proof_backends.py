@@ -12,6 +12,12 @@ PARAMS = Podr2Params(n=8, s=4)
 SK, PK = keygen(b"backend-tee")
 
 
+def stage_samples(reg) -> dict:
+    """{sample name: value} of a registry's unlabelled samples."""
+    return {name: v for metric in reg.metrics()
+            for name, labels, v in metric.samples() if not labels}
+
+
 def make_challenge(indices, seed=b"x"):
     randoms = tuple(
         (seed + i.to_bytes(2, "little")).ljust(20, b"\x55") for i in indices
@@ -84,20 +90,30 @@ class TestParity:
         assert cpu == [False] * 4 == xla
 
     def test_profile_stages_breakdown(self, proved):
-        """profile_stages: the per-stage wall-clock attribution bench.py
-        logs — verdicts unchanged, every stage charged."""
+        """The staged path's stage histograms: verdicts unchanged, every
+        stage of the check observed, the front end once."""
+        from cess_tpu.proof.xla_backend import proof_stage_registry
+
         _, items = proved
-        backend = XlaBackend(profile_stages=True)
+        reg = proof_stage_registry()
+        before = stage_samples(reg)
+        backend = XlaBackend()
         assert backend.verify_batch(PK, items, b"round", PARAMS) == (
             [True] * len(items)
         )
-        stages = backend.stage_seconds
-        assert set(stages) == {
-            "host_prep", "u_fold", "sigma_fold", "chunk_program",
-            "pairing",
+        after = stage_samples(reg)
+        delta = {k: after[k] - before.get(k, 0.0) for k in after}
+        seen = {k[len("cess_proof_stage_"):-len("_seconds_count")]
+                for k, v in delta.items()
+                if k.endswith("_seconds_count") and v > 0}
+        assert seen == {
+            "front_end", "host_prep", "u_fold", "sigma_fold",
+            "chunk_program", "pairing",
         }
-        assert all(v >= 0 for v in stages.values())
-        assert stages["pairing"] > 0
+        assert delta["cess_proof_stage_front_end_seconds_count"] == 1
+        assert delta["cess_proof_stage_pairing_seconds_sum"] > 0
+        assert (delta["cess_proof_stage_front_end_seconds_sum"]
+                <= delta["cess_proof_stage_host_prep_seconds_sum"])
 
     def test_empty_batch(self):
         for backend in (CpuBackend(), XlaBackend()):

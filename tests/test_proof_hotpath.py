@@ -11,6 +11,8 @@ compile tiny on the CPU mesh.
 
 import hashlib
 import random
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -31,6 +33,12 @@ from cess_tpu.proof import CpuBackend, XlaBackend, frontend, fused
 pytestmark = pytest.mark.proof_hotpath
 
 RND = random.Random(0x1207)
+
+
+def stage_samples(reg) -> dict:
+    """{sample name: value} of a registry's unlabelled samples."""
+    return {name: v for metric in reg.metrics()
+            for name, labels, v in metric.samples() if not labels}
 
 
 def _scalar_decompress(blob, check_subgroup):
@@ -366,16 +374,111 @@ class TestStagedPathParity:
         assert cpu == xla == [True, False, True, True]
 
     def test_staged_fused_same_stage_names(self, proved10):
-        from cess_tpu.proof.xla_backend import STAGE_NAMES
+        from cess_tpu.proof.xla_backend import (
+            STAGE_NAMES,
+            proof_stage_registry,
+        )
 
-        staged = XlaBackend(profile_stages=True, fused=False)
-        assert staged.verify_batch(PK, proved10[:2], b"st", PARAMS) == (
-            [True, True]
-        )
-        fusedb = XlaBackend(profile_stages=True, fused=True)
-        assert fusedb.verify_batch(PK, proved10[:2], b"st", PARAMS) == (
-            [True, True]
-        )
-        assert set(staged.stage_seconds) <= set(STAGE_NAMES)
-        assert set(fusedb.stage_seconds) <= set(STAGE_NAMES)
-        assert "dispatch_wait" in fusedb.stage_seconds
+        reg = proof_stage_registry()
+
+        def observed(backend):
+            before = stage_samples(reg)
+            assert backend.verify_batch(PK, proved10[:2], b"st", PARAMS) == (
+                [True, True]
+            )
+            after = stage_samples(reg)
+            return {n for n in STAGE_NAMES
+                    if after[f"cess_proof_stage_{n}_seconds_count"]
+                    > before.get(f"cess_proof_stage_{n}_seconds_count", 0)}
+
+        staged = observed(XlaBackend(fused=False))
+        fusedb = observed(XlaBackend(fused=True))
+        assert {"front_end", "host_prep", "u_fold", "pairing"} <= (
+            staged & fusedb)
+        assert "dispatch_wait" in fusedb and "launch" in fusedb
+        assert "prep" in fusedb and "chunk_program" not in fusedb
+        assert "chunk_program" in staged and "launch" not in staged
+
+
+class SpanRecorder:
+    """Stands in for jax.profiler.TraceAnnotation: records (name,
+    thread, start, end) of every stage span."""
+
+    def __init__(self):
+        self.spans = []
+        self.lock = threading.Lock()
+
+    def __call__(self, name):
+        rec = self
+
+        class Span:
+            def __enter__(self):
+                self.t0 = time.perf_counter()
+
+            def __exit__(self, *exc):
+                with rec.lock:
+                    rec.spans.append((name, threading.current_thread().name,
+                                      self.t0, time.perf_counter()))
+
+        return Span()
+
+    def named(self, stage):
+        return [s for s in self.spans if s[0] == "cess.proof." + stage]
+
+
+class TestStageSpans:
+    """The fused pipeline's stage spans and counters, on a two-chunk
+    batch of 7 proofs (chunks of 4 and 3, both padded to 4 slots)."""
+
+    def test_fused_two_chunk_spans(self, proved10, one_shape, monkeypatch):
+        from cess_tpu.proof.xla_backend import proof_stage_registry
+        from cess_tpu.utils import stages
+
+        rec = SpanRecorder()
+        monkeypatch.setattr(stages, "TraceAnnotation", rec)
+        reg = proof_stage_registry()
+        before = stage_samples(reg)
+        assert XlaBackend(fused=True).verify_batch(
+            PK, proved10[:7], b"spans", PARAMS) == [True] * 7
+        after = stage_samples(reg)
+        delta = {k: after[k] - before.get(k, 0.0) for k in after}
+
+        counts = {s: delta[f"cess_proof_stage_{s}_seconds_count"]
+                  for s in ("front_end", "host_prep", "prep", "launch",
+                            "dispatch_wait", "u_fold", "pairing")}
+        assert counts == {"front_end": 1, "host_prep": 2, "prep": 2,
+                          "launch": 3, "dispatch_wait": 1, "u_fold": 1,
+                          "pairing": 1}
+        for stage, n in counts.items():
+            assert len(rec.named(stage)) == n, stage
+        assert delta["cess_proof_chunk_proofs_total"] == 7
+        assert delta["cess_proof_chunk_slots_total"] == 8
+
+        caller = threading.current_thread().name
+        (fe,) = rec.named("front_end")
+        first_prep = min(rec.named("host_prep"), key=lambda s: s[2])
+        assert fe[1] == first_prep[1] == caller
+        assert first_prep[2] <= fe[2] <= fe[3] <= first_prep[3]
+        workers = {s[1] for s in rec.named("prep")}
+        assert len(workers) == 1 and caller not in workers
+        for stage in ("host_prep", "launch", "dispatch_wait", "u_fold",
+                      "pairing"):
+            assert {s[1] for s in rec.named(stage)} == {caller}, stage
+
+    def test_rejected_batch_observes_nothing(self, proved10, monkeypatch):
+        from cess_tpu.proof.xla_backend import proof_stage_registry
+        from cess_tpu.utils import stages
+
+        rec = SpanRecorder()
+        monkeypatch.setattr(stages, "TraceAnnotation", rec)
+        name, ch, proof = proved10[0]
+        bad = [(name, ch, Podr2Proof(proof.sigma, list(proof.mu)[:-1]))]
+        reg = proof_stage_registry()
+        before = stage_samples(reg)
+        assert not fused.combined_check_fused(PK, bad, b"rej", PARAMS)
+        after = stage_samples(reg)
+        assert {k: v for k, v in after.items()
+                if v != before.get(k, 0.0)} == {}
+        # the spans were opened and closed, but no stage was observed
+        assert {s[0] for s in rec.spans} == {"cess.proof.host_prep",
+                                             "cess.proof.front_end"}

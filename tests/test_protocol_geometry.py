@@ -4,8 +4,8 @@ reference geometry: primitives/common/src/lib.rs:61-62,
 c-pallets/audit/src/lib.rs:906) through the xla backend.
 
 Marked slow: several minutes of XLA compiles on the CPU test mesh.  Run
-with RUN_SLOW=1 (CI) or `pytest --runslow`; bench.py exercises the same
-geometry on the real chip every round.
+with RUN_SLOW=1 (CI) or `pytest --runslow`; the benchmark's verify cells
+(perfbench/) exercise the same geometry on the real chip.
 """
 
 import random

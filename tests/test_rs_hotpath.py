@@ -10,6 +10,8 @@ proof/fused.py)."""
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 import pytest
 
@@ -20,6 +22,12 @@ pytestmark = pytest.mark.rs_hotpath
 
 PATHS = ("bitplane", "gather")
 RS21_PATTERNS = ([0, 1], [0, 2], [1, 2])  # every 2-of-3 survivor set
+
+
+def stage_samples(reg) -> dict:
+    """{sample name: value} of a registry's unlabelled samples."""
+    return {name: v for metric in reg.metrics()
+            for name, labels, v in metric.samples() if not labels}
 
 
 @pytest.fixture(scope="module")
@@ -286,12 +294,67 @@ class TestConstantCacheAndTelemetry:
         rendered = reg.render()
         for name in rs.RS_STAGE_NAMES:
             assert f"cess_rs_{name}_seconds" in rendered
-        stages = {}
         code = rs.RSCode(2, 1, path="gather", tile=2048)
         data = np.random.default_rng(23).integers(
             0, 256, size=(2, 9000), dtype=np.uint8
         )
-        rs.RSStream(code, stages=stages).run(data)
-        assert set(stages) == set(rs.RS_STAGE_NAMES)
-        assert all(v >= 0.0 for v in stages.values())
+        before = stage_samples(reg)
+        rs.RSStream(code).run(data)
+        after = stage_samples(reg)
+        for name in rs.RS_STAGE_NAMES:
+            key = f"cess_rs_{name}_seconds"
+            assert after[key + "_count"] > before.get(key + "_count", 0)
+            assert after[key + "_sum"] >= before.get(key + "_sum", 0.0)
         assert "cess_rs_bytes_total" in reg.render()
+
+    def test_slab_counts_and_nested_stages(self, monkeypatch):
+        """run_batch over three survivor patterns (3, 2 and 2 segments,
+        slabs of 2): exact slab counters; gather and put inside pack,
+        pull and scatter inside unpack, each a `cess.rs.*` span."""
+        from cess_tpu.utils import stages
+
+        spans = []
+
+        class Span:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                self.t0 = time.perf_counter()
+
+            def __exit__(self, *exc):
+                spans.append((self.name, self.t0, time.perf_counter()))
+
+        monkeypatch.setattr(stages, "TraceAnnotation", Span)
+        code = rs.RSCode(2, 1, path="gather")
+        rng = np.random.default_rng(5)
+        present = [[0, 1]] * 3 + [[0, 2]] * 2 + [[1, 2]] * 2
+        surv = rng.integers(0, 256, size=(7, 2, 64), dtype=np.uint8)
+        reg = rs.rs_stage_registry()
+        before = stage_samples(reg)
+        got = rs.RSStream(code, present=present, slab=2).run_batch(surv)
+        after = stage_samples(reg)
+        delta = {k: after[k] - before.get(k, 0.0) for k in after}
+        for s in range(7):
+            want = gf256.rs_decode_ref(surv[s], present[s], 2, 1)
+            assert np.array_equal(got[s], want)
+        assert delta["cess_rs_slab_segments_total"] == 7
+        assert delta["cess_rs_slab_slots_total"] == 8  # slabs 2+2, 2, 2
+        assert delta["cess_rs_pack_seconds_count"] == 4
+        assert delta["cess_rs_scatter_seconds_count"] == 3  # one a group
+
+        def total(stage):
+            return delta[f"cess_rs_{stage}_seconds_sum"]
+
+        assert total("gather") + total("put") <= total("pack")
+        assert total("pull") + total("scatter") <= total("unpack")
+        names = {name for name, _, _ in spans}
+        assert names == {"cess.rs." + n for n in rs.RS_STAGE_NAMES}
+
+        def within(inner, outer):
+            outs = [(a, b) for n, a, b in spans if n == "cess.rs." + outer]
+            return all(any(a <= s <= e <= b for a, b in outs)
+                       for n, s, e in spans if n == "cess.rs." + inner)
+
+        assert within("gather", "pack") and within("put", "pack")
+        assert within("pull", "unpack") and within("scatter", "unpack")

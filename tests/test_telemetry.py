@@ -173,6 +173,11 @@ class TestTracer:
         assert "author.execute" in text2
 
 
+def _unlabelled(plane) -> dict:
+    return {name: v for metric in plane.registry().metrics()
+            for name, labels, v in metric.samples() if not labels}
+
+
 class TestOverheadGuard:
     """The always-on instrumentation must be invisible next to the
     work it wraps (~0.4 s pairings, ms-scale folds): measured budget
@@ -197,6 +202,38 @@ class TestOverheadGuard:
             h.observe(0.001 * (i % 7))
         per = (time.perf_counter() - t0) / n
         assert per < 50e-6, f"observe overhead {per * 1e6:.1f}µs"
+
+    def test_stage_overhead_micros(self):
+        """A data-plane stage (profiler span + histogram observe) with
+        the profiler off."""
+        from cess_tpu.utils import stages
+
+        plane = stages.StagePlane("test", ("x",), "t_stage_{}_seconds",
+                                  "{}", {})
+        n = 2000
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with plane.stage("x"):
+                pass
+        per = (time.perf_counter() - t0) / n
+        assert _unlabelled(plane)["t_stage_x_seconds_count"] == n
+        assert per < 100e-6, f"stage overhead {per * 1e6:.1f}µs"
+
+    def test_stage_switch_off(self, monkeypatch):
+        """CESS_STAGE_METRICS=0: no span, no observation, no count."""
+        from cess_tpu.utils import stages
+
+        opened = []
+        monkeypatch.setattr(stages, "TraceAnnotation", opened.append)
+        monkeypatch.setattr(stages, "ENABLED", False)
+        plane = stages.StagePlane("test", ("x",), "t_off_{}_seconds", "{}",
+                                  {"n": ("t_off_total", "n")})
+        with plane.stage("x"):
+            plane.count(n=3)
+        assert opened == []
+        samples = _unlabelled(plane)
+        assert samples["t_off_x_seconds_count"] == 0
+        assert samples["t_off_total"] == 0
 
 
 # ------------------------------------------------------------ events

@@ -8,8 +8,8 @@ trusted, so the measured rate is the rate of *verified* reads, not of
 blind RPC round trips.
 
 Workers are spread round-robin across the given endpoints, which is
-exactly the horizontal-scaling claim under test (bench.py
-BENCH_ONLY=light: two replicas should beat one).
+exactly the horizontal-scaling claim under test (two replicas should
+beat one).
 
     python tools/read_loadgen.py --replicas 127.0.0.1:19944,... \
         --chain local --clients 8 --reads 200

@@ -85,8 +85,9 @@ class FleetCollector:
     """Samples a fleet over a window, then builds the report."""
 
     IMPORT_STAGES = ("sig_batch", "execute", "snapshot")
-    PROOF_STAGES = ("host_prep", "u_fold", "sigma_fold",
-                    "chunk_program", "dispatch_wait", "pairing")
+    PROOF_STAGES = ("front_end", "host_prep", "prep", "launch", "u_fold",
+                    "sigma_fold", "chunk_program", "dispatch_wait",
+                    "pairing")
 
     def __init__(self, nodes: list[tuple[str, int]], timeout: float = 5.0):
         self.nodes = list(nodes)
