@@ -318,3 +318,37 @@ def xmd_u_indexed(names: list[bytes], name_ids, indices, dst: bytes,
     u = np.frombuffer(out_u.raw, dtype=np.uint8).reshape(n, 2, 48)
     flags = np.frombuffer(out_f.raw, dtype=np.uint8)[:n]
     return u, flags
+
+
+def g1_decompress_batch(xs: list[int], signs: list[bool] | None,
+                        threads: int = 8) -> list[int | None]:
+    """Square roots for compressed-G1 decompression, in native threads
+    with the GIL released: per canonical x, the y of the point
+    (x, y) on y² = x³ + 4 whose sign flag (y > p − y) is the lane's
+    `signs` entry, or None where x³ + 4 has no root.  With `signs`
+    None, `xs` are canonical radicands a and each result is
+    a^((p+1)/4) where that is a root (fp_sqrt's value).  Bit-identical
+    to the pow() loop in ops/bls12_381.py (tests/test_native.py)."""
+    blsmap_init()
+    lib = _blsmap_lib()
+    # typed here, not in _blsmap_lib: a core built before this export
+    # raises AttributeError for this call alone, and the caller falls back
+    fn = lib.cess_blsmap_g1_decompress_batch
+    fn.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
+    ]
+    fn.restype = ctypes.c_int
+    n = len(xs)
+    if signs is not None and len(signs) != n:
+        raise ValueError("one sign per x coordinate")
+    out_y = ctypes.create_string_buffer(48 * n)
+    status = ctypes.create_string_buffer(n)
+    rc = fn(b"".join(x.to_bytes(48, "big") for x in xs),
+            None if signs is None else bytes(map(bool, signs)),
+            n, out_y, status, threads)
+    if rc != 0:
+        raise RuntimeError(f"g1_decompress_batch failed: {rc}")
+    ys = out_y.raw
+    return [None if bad else int.from_bytes(ys[48 * i : 48 * i + 48], "big")
+            for i, bad in enumerate(status.raw)]

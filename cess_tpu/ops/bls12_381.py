@@ -522,39 +522,59 @@ def g1_decompress_unchecked(data: bytes) -> G1Point:
 # ---------------------------------------------------- batched decompression
 #
 # One G1Point.from_bytes costs one fp_sqrt — a (p+1)/4 exponentiation,
-# ~0.3 ms per σ — plus a ~3 ms host Python subgroup ladder.  At
-# batch-verify scale that is the classic per-proof host residue.  Two
-# facts shape the batch form:
+# ~0.5 ms per σ in CPython's pow() — plus a ~3 ms host Python subgroup
+# ladder.  At batch-verify scale that is the classic per-proof host
+# residue.  Two facts shape the batch form:
 #
-#  * Square roots do not batch: Montgomery's trick turns N inversions
-#    into one because inv(a_i) = inv(Πa)·Π_{j≠i}a_j, but the root of a
-#    product gives only the PRODUCT of the roots — there is no
+#  * Square roots do not batch by Montgomery's trick: it turns N
+#    inversions into one because inv(a_i) = inv(Πa)·Π_{j≠i}a_j, but the
+#    root of a product gives only the PRODUCT of the roots — there is no
 #    per-element relation to unwind, so each lane pays its own
-#    exponentiation.  CPython's pow() (C sliding-window) was measured
-#    5× faster per lane than a shared square-and-multiply chain over
-#    vectorised numpy uint64 limbs (the ops/g1.py design scaled to
-#    host), so the chain stays in C and the batch amortises the
-#    Python-level validation instead.
+#    exponentiation.  What the batch buys is where that chain runs: the
+#    native core's Montgomery chain (native/blsmap.cpp, the one its
+#    hash-to-curve uses) in threads with the GIL released, one call for
+#    the batch — ~6× one pow() per lane on one thread, and it scales with
+#    the cores.  A shared chain over vectorised numpy uint64 limbs was
+#    measured 5× slower per lane than pow(), so the host fallback (no
+#    core, or a build without the export) stays the pow() loop.
 #  * The subgroup ladder is the part worth moving: check_subgroup=False
 #    defers it so callers run ONE batched device [r]-chain
 #    (ops/glv.py subgroup_mask) over the whole batch — bit-identical
 #    rejection, none of the per-point host milliseconds.
 #
 # Bit-identity with the scalar path (fp_sqrt / from_bytes /
-# g1_decompress_unchecked), including the rejection set, is asserted in
-# tests/test_proof_hotpath.py.
+# g1_decompress_unchecked), including the rejection set, on both the
+# native and the fallback roots, is asserted in tests/test_proof_hotpath.py.
+
+# threads of a native root batch (as ops/h2c.py xmd_u); the core runs a
+# batch below two lanes a thread on the calling thread alone
+_SQRT_THREADS = 8
+
+
+def _native_roots(values: list[int], signs: list[bool] | None):
+    """native.g1_decompress_batch, or None when the core is missing or
+    predates the export (the caller then takes the pow() loop)."""
+    try:
+        from .. import native
+
+        return native.g1_decompress_batch(values, signs, _SQRT_THREADS)
+    except (AssertionError, AttributeError, OSError, RuntimeError):
+        return None
 
 
 def fp_sqrt_batch(values: list[int]) -> list[int | None]:
-    """Batch fp_sqrt — literally a loop over the scalar helper (see the
-    module comment above: per-lane C pow() is the fastest chain), kept
-    as the batch seam so a future backend that CAN amortise roots slots
-    in without touching callers."""
-    return [fp_sqrt(v % P) for v in values]
+    """fp_sqrt over a batch: the native core's threaded chain when it is
+    built, else a loop over the scalar helper; the same values either
+    way."""
+    values = [v % P for v in values]
+    roots = _native_roots(values, None)
+    if roots is None:
+        roots = [fp_sqrt(v) for v in values]
+    return roots
 
 
 def g1_decompress_batch(
-    blobs: list[bytes], check_subgroup: bool = True
+    blobs: list[bytes], check_subgroup: bool = True, on_native=None
 ) -> list[G1Point]:
     """Batched compressed-G1 decompression, bit-identical to a loop of
     G1Point.from_bytes (check_subgroup=True) or g1_decompress_unchecked
@@ -565,12 +585,12 @@ def g1_decompress_batch(
     FIRST invalid item of each validation phase; callers that need
     per-item verdicts bisect, exactly as they do over the scalar path.
 
-    The square roots stay per-lane C pow() (fp_sqrt_batch — see the
-    module comment for why they don't batch); what the batch form
-    amortises is the Python-level validation and, via
-    check_subgroup=False, the subgroup ladder.  check_subgroup=False
-    is the fast path for verifiers that defer the subgroup test to the
-    batched device [r]-chain (ops/glv.py subgroup_mask)."""
+    The encodings are validated here; the square roots of the surviving
+    lanes run in one native batch (see the module comment), and
+    `on_native`, when given, is called with the number of lanes whose
+    roots the native core computed.  check_subgroup=False is the fast
+    path for verifiers that defer the subgroup test to the batched
+    device [r]-chain (ops/glv.py subgroup_mask)."""
     n = len(blobs)
     out: list[G1Point | None] = [None] * n
     lanes: list[int] = []
@@ -594,12 +614,16 @@ def g1_decompress_batch(
         xs.append(x)
         large.append(bool(flags & 0x20))
     if lanes:
-        roots = fp_sqrt_batch([(x * x % P * x + G1Point.B) % P for x in xs])
-        for k, x, y, lg in zip(lanes, xs, roots, large):
+        ys = _native_roots(xs, large)
+        if ys is None:  # the pow() loop, as G1Point.from_bytes
+            ys = [fp_sqrt((x * x % P * x + G1Point.B) % P) for x in xs]
+            ys = [y if y is None or lg == (y > P - y) else P - y
+                  for y, lg in zip(ys, large)]
+        elif on_native is not None:
+            on_native(len(lanes))
+        for k, x, y in zip(lanes, xs, ys):
             if y is None:
                 raise ValueError("point not on curve")
-            if lg != (y > P - y):
-                y = P - y
             out[k] = G1Point(x, y)
         if check_subgroup:
             for k in lanes:

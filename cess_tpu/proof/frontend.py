@@ -21,7 +21,8 @@ staged XlaBackend path:
 
 `front_end` is the serial part both pipelines run first (pk and σ
 decoding, the encode pass, the μ range check, the ρ transcript); a
-batch it rejects raises `Rejected`.
+batch it rejects raises `Rejected`.  σ decoding is its own stage,
+`sigma_decode`, nested in the caller's `front_end`.
 
 Everything here is bit-identical to the scalar forms it replaces —
 asserted in tests/test_proof_hotpath.py (the `proof_hotpath` CI gate).
@@ -60,15 +61,18 @@ def front_end(pk: bytes, items, seed: bytes, params) -> FrontEnd:
     """Decode and validate a batch and derive its ρ weights from ONE
     encode pass; raises Rejected where ops/podr2.py batch_verify would
     return False before any group work."""
+    from .xla_backend import PROOF_STAGES
+
     try:
         pk_point = G2Point.from_bytes(pk)
     except ValueError:
         raise Rejected("pk") from None
-    sigmas = decompress_sigmas(items)
-    if sigmas is None:
-        raise Rejected("sigma")
     if any(len(p.mu) != params.s for _, _, p in items):
         raise Rejected("mu width")
+    with PROOF_STAGES.stage("sigma_decode"):
+        sigmas = decompress_sigmas(items)
+        if sigmas is None:
+            raise Rejected("sigma")
     encs = encode_proofs(items)
     if encs is None:
         raise Rejected("mu encoding")
@@ -88,13 +92,22 @@ def decompress_sigmas(items) -> list | None:
     runs one batched device [r]-chain — ops/glv.py subgroup_mask).
     Returns None when any blob is malformed: the scalar path raises
     ValueError there, which every combined check maps to the whole-batch
-    False verdict (bisection then isolates the bad items)."""
+    False verdict (bisection then isolates the bad items).  A decoded
+    batch counts its σ (`sigma_lanes`) and those whose square roots the
+    native core computed (`sigma_native_lanes`)."""
+    from .xla_backend import PROOF_STAGES
+
+    native: list[int] = []
     try:
-        return bls.g1_decompress_batch(
-            [p.sigma for _, _, p in items], check_subgroup=False
+        sigmas = bls.g1_decompress_batch(
+            [p.sigma for _, _, p in items], check_subgroup=False,
+            on_native=native.append,
         )
     except ValueError:
         return None
+    PROOF_STAGES.count(sigma_lanes=len(sigmas),
+                       sigma_native_lanes=sum(native))
+    return sigmas
 
 
 def encode_proofs(items) -> list[bytes] | None:

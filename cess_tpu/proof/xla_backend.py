@@ -67,10 +67,12 @@ _DEVICE_H2C_MIN_PAIRS = 256
 # (utils/stages.py), so a device trace names the stage the host was in.
 #
 # BOTH verify pipelines mark `front_end` (decode, encode pass, ρ
-# transcript; nested in the first `host_prep`) and `host_prep`,
-# `u_fold`, `pairing`.  The staged path below adds sigma_fold and
-# chunk_program (the H-side folds, real work); the fused single-program
-# path (proof/fused.py combined_check_fused) adds `prep` (one chunk's
+# transcript; nested in the first `host_prep`) with `sigma_decode` (σ
+# decompression) inside it, and `host_prep`, `u_fold`, `pairing`; both
+# count the σ decoded and those whose roots came from the native core.
+# The staged path below adds sigma_fold and chunk_program (the H-side
+# folds, real work); the fused single-program path
+# (proof/fused.py combined_check_fused) adds `prep` (one chunk's
 # packing, on the prefetch worker), `launch` (upload + asynchronous
 # dispatch of each chunk, and of the partial reduction) and
 # `dispatch_wait` (the block on device results after every chunk is in
@@ -83,8 +85,9 @@ _DEVICE_H2C_MIN_PAIRS = 256
 # CESS_STAGE_METRICS=0 switches spans, histograms and counters off for
 # A/B measurement.
 
-STAGE_NAMES = ("front_end", "host_prep", "prep", "launch", "u_fold",
-               "sigma_fold", "chunk_program", "dispatch_wait", "pairing")
+STAGE_NAMES = ("front_end", "sigma_decode", "host_prep", "prep", "launch",
+               "u_fold", "sigma_fold", "chunk_program", "dispatch_wait",
+               "pairing")
 
 PROOF_STAGES = StagePlane(
     "proof", STAGE_NAMES, "cess_proof_stage_{}_seconds",
@@ -99,6 +102,11 @@ PROOF_STAGES = StagePlane(
                          "live proofs in launched fused chunks"),
         "chunk_slots": ("cess_proof_chunk_slots_total",
                         "padded proof slots of launched fused chunks"),
+        "sigma_lanes": ("cess_proof_sigma_lanes_total",
+                        "sigma points decoded by the verify front end"),
+        "sigma_native_lanes": ("cess_proof_sigma_native_lanes_total",
+                               "decoded sigma points whose square roots "
+                               "came from the native core"),
     })
 
 

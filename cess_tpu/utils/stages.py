@@ -12,9 +12,11 @@ block.  One block does two things over one interval:
     process-wide registry (merged into node `system_metrics`).
 
 A block that raises observes nothing: a batch the front end rejects
-records no stage.  Outside a trace the span costs one small object;
-CESS_STAGE_METRICS=0 turns every block into a shared no-op context (no
-span, no observation) and every count into a no-op.
+records no stage, unless a stage nested in the front end ended before
+the rejection (σ decoding, then a μ out of range).  Outside a trace
+the span costs one small object; CESS_STAGE_METRICS=0 turns every
+block into a shared no-op context (no span, no observation) and every
+count into a no-op.
 """
 
 from __future__ import annotations

@@ -544,6 +544,62 @@ static void hash_one(const uint8_t* msg, size_t msg_len, const uint8_t* dst,
   fp_to_bytes_be(ayp, out + 48);
 }
 
+// ----------------------------------------------------------- decompression
+
+// One lane of cess_blsmap_g1_decompress_batch: y = a^((p+1)/4) for
+// a = x³ + 4 (curve) or a = x itself, the check y² = a, then the root
+// the sign names.  Returns 1 where a has no root.
+static uint8_t sqrt_lane(const uint8_t in[48], bool curve, int sign,
+                         uint8_t out[48]) {
+  Fp a, am, y, ysq;
+  for (int i = 0; i < NL; ++i) {
+    uint64_t limb = 0;
+    for (int k = 0; k < 8; ++k) limb = (limb << 8) | in[48 - 8 * (i + 1) + k];
+    a.v[i] = limb;
+  }
+  to_mont(a, am);
+  if (curve) {
+    Fp x2;
+    mont_sqr(am, x2);
+    mont_mul(x2, am, x2);
+    add_mod(x2, FOUR_M, am);
+  }
+  mont_pow(am, SQRT_EXP, 48, y);
+  mont_sqr(y, ysq);
+  if (!eq(ysq, am)) {
+    std::memset(out, 0, 48);
+    return 1;
+  }
+  from_mont(y, y);
+  if (sign >= 0) {
+    Fp neg = P;
+    sub_nocheck(neg, y);  // p − y, as the scalar path computes it
+    if ((sign != 0) != !geq(neg, y)) y = neg;
+  }
+  fp_to_bytes_be(y, out);
+  return 0;
+}
+
+// Runs work(lo, hi) over [0, n): on the calling thread below two lanes a
+// thread, so a small batch pays no thread start-up; else in n_threads
+// threads of near-equal slices.
+template <class Work>
+static void split_lanes(uint64_t n, uint64_t n_threads, const Work& work) {
+  if (n_threads <= 1 || n < 2 * n_threads) {
+    work(0, n);
+    return;
+  }
+  std::vector<std::thread> pool;
+  uint64_t chunk = (n + n_threads - 1) / n_threads;
+  for (uint64_t t = 0; t < n_threads; ++t) {
+    uint64_t lo = t * chunk;
+    uint64_t hi = lo + chunk < n ? lo + chunk : n;
+    if (lo >= hi) break;
+    pool.emplace_back(work, lo, hi);
+  }
+  for (auto& th : pool) th.join();
+}
+
 }  // namespace blsmap
 
 // ----------------------------------------------------------- exports
@@ -648,19 +704,7 @@ CESS_EXPORT int cess_blsmap_xmd_u_batch(
       out_flags[i] = u_pair_from_uniform(uniform, out_u + 96 * i);
     }
   };
-  if (n_threads <= 1 || n < 2 * n_threads) {
-    work(0, n);
-    return 0;
-  }
-  std::vector<std::thread> pool;
-  uint64_t chunk = (n + n_threads - 1) / n_threads;
-  for (uint64_t t = 0; t < n_threads; ++t) {
-    uint64_t lo = t * chunk;
-    uint64_t hi = lo + chunk < n ? lo + chunk : n;
-    if (lo >= hi) break;
-    pool.emplace_back(work, lo, hi);
-  }
-  for (auto& th : pool) th.join();
+  split_lanes(n, n_threads, work);
   return 0;
 }
 
@@ -696,19 +740,7 @@ CESS_EXPORT int cess_blsmap_xmd_u_indexed(
       out_flags[i] = u_pair_from_uniform(uniform, out_u + 96 * i);
     }
   };
-  if (n_threads <= 1 || n < 2 * n_threads) {
-    work(0, n);
-    return 0;
-  }
-  std::vector<std::thread> pool;
-  uint64_t chunk = (n + n_threads - 1) / n_threads;
-  for (uint64_t t = 0; t < n_threads; ++t) {
-    uint64_t lo = t * chunk;
-    uint64_t hi = lo + chunk < n ? lo + chunk : n;
-    if (lo >= hi) break;
-    pool.emplace_back(work, lo, hi);
-  }
-  for (auto& th : pool) th.join();
+  split_lanes(n, n_threads, work);
   return 0;
 }
 
@@ -728,18 +760,29 @@ CESS_EXPORT int cess_blsmap_hash_g1_batch(
       hash_one(msg, len, dst, dst_len, out + 96 * i);
     }
   };
-  if (n_threads <= 1 || n < 2 * n_threads) {
-    work(0, n);
-    return 0;
-  }
-  std::vector<std::thread> pool;
-  uint64_t chunk = (n + n_threads - 1) / n_threads;
-  for (uint64_t t = 0; t < n_threads; ++t) {
-    uint64_t lo = t * chunk;
-    uint64_t hi = lo + chunk < n ? lo + chunk : n;
-    if (lo >= hi) break;
-    pool.emplace_back(work, lo, hi);
-  }
-  for (auto& th : pool) th.join();
+  split_lanes(n, n_threads, work);
+  return 0;
+}
+
+// Compressed-G1 decompression's square roots (cess_tpu/ops/bls12_381.py
+// g1_decompress_batch, which validates the encodings first).  `xs` holds
+// n canonical big-endian x coordinates, flag bits stripped; per lane
+// y = (x³ + 4)^((p+1)/4), status 1 where y² ≠ x³ + 4 (x is on no point),
+// and out_y the root the lane's sign byte names: p − y where the sign
+// disagrees with y > p − y (the ZCash flag rule).  With `signs` null the
+// inputs are the radicands themselves and out_y the root
+// a^((p+1)/4) as is: fp_sqrt_batch's form.
+CESS_EXPORT int cess_blsmap_g1_decompress_batch(
+    const uint8_t* xs, const uint8_t* signs, uint64_t n, uint8_t* out_y,
+    uint8_t* status, uint64_t n_threads) {
+  using namespace blsmap;
+  if (!INITED) return 1;
+  auto work = [&](uint64_t lo, uint64_t hi) {
+    for (uint64_t i = lo; i < hi; ++i) {
+      status[i] = sqrt_lane(xs + 48 * i, signs != nullptr,
+                            signs ? signs[i] : -1, out_y + 48 * i);
+    }
+  };
+  split_lanes(n, n_threads, work);
   return 0;
 }

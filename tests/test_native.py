@@ -2,6 +2,7 @@
 
 import hashlib
 import os
+import random
 
 import numpy as np
 import pytest
@@ -129,3 +130,47 @@ class TestBlsMap:
         batch = podr2.chunk_points_batch(pairs)
         singles = [podr2.chunk_point(n, i) for n, i in pairs]
         assert batch == singles
+
+    @pytest.mark.parametrize("n", [11, 41])  # below and above 2 * 8 lanes
+    def test_decompress_roots_threads_agree(self, lib, n):
+        """The decompression export gives the same y and status on one
+        thread and on eight, in both modes, and the pow() loop's values:
+        curve lanes (x³ + 4, root the sign names) and raw radicands."""
+        from cess_tpu.ops import bls12_381 as bls
+
+        P = bls.P
+        rnd = random.Random(n)
+        xs = [rnd.getrandbits(381) % P for _ in range(n - 1)] + [0]
+        signs = [bool(rnd.getrandbits(1)) for _ in xs]
+        want = []
+        for x, large in zip(xs, signs):
+            y = bls.fp_sqrt((x**3 + 4) % P)
+            if y is not None and large != (y > P - y):
+                y = P - y
+            want.append(y)
+        assert None in want and any(want)
+        for threads in (1, 8):
+            assert native.g1_decompress_batch(xs, signs, threads) == want
+            assert native.g1_decompress_batch(xs, None, threads) == [
+                bls.fp_sqrt(x) for x in xs]
+
+    def test_stale_library_falls_back(self, lib, monkeypatch):
+        """A core built before the decompression export: the roots come
+        from the pow() loop, with no error and no native lanes."""
+        from cess_tpu.ops import bls12_381 as bls
+
+        class Stale:
+            def __getattr__(self, name):
+                if name == "cess_blsmap_g1_decompress_batch":
+                    raise AttributeError(name)
+                return getattr(lib, name)
+
+        monkeypatch.setattr(native, "load", lambda: Stale())
+        blobs = [bls.G1_GENERATOR.mul(k).to_bytes() for k in (3, 5, 7)]
+        native_lanes = []
+        got = bls.g1_decompress_batch(blobs, check_subgroup=False,
+                                      on_native=native_lanes.append)
+        assert got == [bls.g1_decompress_unchecked(b) for b in blobs]
+        assert native_lanes == []
+        vals = [4, 5, bls.P - 1]
+        assert bls.fp_sqrt_batch(vals) == [bls.fp_sqrt(v) for v in vals]

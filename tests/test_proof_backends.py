@@ -107,8 +107,8 @@ class TestParity:
                 for k, v in delta.items()
                 if k.endswith("_seconds_count") and v > 0}
         assert seen == {
-            "front_end", "host_prep", "u_fold", "sigma_fold",
-            "chunk_program", "pairing",
+            "front_end", "sigma_decode", "host_prep", "u_fold",
+            "sigma_fold", "chunk_program", "pairing",
         }
         assert delta["cess_proof_stage_front_end_seconds_count"] == 1
         assert delta["cess_proof_stage_pairing_seconds_sum"] > 0

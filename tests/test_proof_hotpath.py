@@ -71,11 +71,35 @@ def _nonsubgroup_blob() -> bytes:
             return _compress_raw(p.x, p.y > P - p.y)
 
 
+@pytest.fixture(scope="module")
+def native_core():
+    """The native core, built from the current sources (make does
+    nothing when the library is up to date)."""
+    from cess_tpu import native
+
+    assert native.build(), "native build failed (g++ required)"
+    native.load.cache_clear()
+    assert native.load() is not None
+
+
+@pytest.fixture(params=["native", "fallback"])
+def roots(request, monkeypatch):
+    """Where the square roots run: the native core's batch call, or the
+    pow() loop taken when no core loads."""
+    from cess_tpu import native
+
+    if request.param == "native":
+        request.getfixturevalue("native_core")
+    else:
+        monkeypatch.setattr(native, "load", lambda: None)
+    return request.param
+
+
 class TestDecompressBatch:
     """g1_decompress_batch must reject exactly the blobs the scalar
     path rejects and return identical points otherwise — both flags,
     infinity, non-residue x, malformed encodings, and (checked mode)
-    non-subgroup points."""
+    non-subgroup points — whichever path computes the roots."""
 
     def valid_blobs(self):
         pts = [G1_GENERATOR.mul(RND.getrandbits(200)) for _ in range(12)]
@@ -84,12 +108,16 @@ class TestDecompressBatch:
         blobs.append(G1Point.infinity().to_bytes())
         return blobs
 
-    def test_valid_batch_identity(self):
+    def test_valid_batch_identity(self, roots):
         blobs = self.valid_blobs()
         for check in (True, False):
-            got = bls.g1_decompress_batch(blobs, check_subgroup=check)
+            native_lanes = []
+            got = bls.g1_decompress_batch(blobs, check_subgroup=check,
+                                          on_native=native_lanes.append)
             want = [_scalar_decompress(b, check) for b in blobs]
             assert got == want
+            # every lane but the point at infinity takes a root
+            assert native_lanes == ([18] if roots == "native" else [])
 
     @pytest.mark.parametrize(
         "blob",
@@ -105,7 +133,7 @@ class TestDecompressBatch:
             b"",
         ],
     )
-    def test_rejects_exactly_the_scalar_set(self, blob):
+    def test_rejects_exactly_the_scalar_set(self, blob, roots):
         for check in (True, False):
             with pytest.raises(ValueError):
                 _scalar_decompress(blob, check)
@@ -117,7 +145,7 @@ class TestDecompressBatch:
                     self.valid_blobs() + [blob], check_subgroup=check
                 )
 
-    def test_subgroup_flag(self):
+    def test_subgroup_flag(self, roots):
         blob = _nonsubgroup_blob()
         with pytest.raises(ValueError):
             G1Point.from_bytes(blob)
@@ -127,9 +155,21 @@ class TestDecompressBatch:
         got = bls.g1_decompress_batch([blob], check_subgroup=False)[0]
         assert got == bls.g1_decompress_unchecked(blob)
 
-    def test_fp_sqrt_batch_identity(self):
-        vals = [RND.getrandbits(400) % P for _ in range(64)] + [0, 1, P - 1]
-        assert bls.fp_sqrt_batch(vals) == [bls.fp_sqrt(v) for v in vals]
+    def test_fp_sqrt_batch_identity(self, roots):
+        rnd = random.Random(0x5A)
+        squares = [pow(rnd.getrandbits(400), 2, P) for _ in range(32)]
+        vals = ([0, 1, P - 1, 4] + squares
+                + [rnd.getrandbits(400) % P for _ in range(32)]
+                + [v + P for v in squares[:2]])  # unreduced
+        want = [bls.fp_sqrt(v) for v in vals]
+        found = [y for y in want if y]
+        assert None in want  # seeded non-residues
+        # roots of either sign class (y > p - y and not), returned as
+        # pow() returns them
+        assert {y > P - y for y in found} == {True, False}
+        assert bls.fp_sqrt_batch(vals) == want
+        assert (bls._native_roots(vals[:4], None) is not None) == (
+            roots == "native")
 
 
 class TestVectorizedPacking:
@@ -444,11 +484,12 @@ class TestStageSpans:
         delta = {k: after[k] - before.get(k, 0.0) for k in after}
 
         counts = {s: delta[f"cess_proof_stage_{s}_seconds_count"]
-                  for s in ("front_end", "host_prep", "prep", "launch",
-                            "dispatch_wait", "u_fold", "pairing")}
-        assert counts == {"front_end": 1, "host_prep": 2, "prep": 2,
-                          "launch": 3, "dispatch_wait": 1, "u_fold": 1,
-                          "pairing": 1}
+                  for s in ("front_end", "sigma_decode", "host_prep",
+                            "prep", "launch", "dispatch_wait", "u_fold",
+                            "pairing")}
+        assert counts == {"front_end": 1, "sigma_decode": 1,
+                          "host_prep": 2, "prep": 2, "launch": 3,
+                          "dispatch_wait": 1, "u_fold": 1, "pairing": 1}
         for stage, n in counts.items():
             assert len(rec.named(stage)) == n, stage
         assert delta["cess_proof_chunk_proofs_total"] == 7
@@ -456,14 +497,33 @@ class TestStageSpans:
 
         caller = threading.current_thread().name
         (fe,) = rec.named("front_end")
+        (sd,) = rec.named("sigma_decode")
         first_prep = min(rec.named("host_prep"), key=lambda s: s[2])
-        assert fe[1] == first_prep[1] == caller
-        assert first_prep[2] <= fe[2] <= fe[3] <= first_prep[3]
+        assert fe[1] == sd[1] == first_prep[1] == caller
+        assert first_prep[2] <= fe[2] <= sd[2] <= sd[3] <= fe[3] <= (
+            first_prep[3])
+        assert delta["cess_proof_sigma_lanes_total"] == 7
         workers = {s[1] for s in rec.named("prep")}
         assert len(workers) == 1 and caller not in workers
         for stage in ("host_prep", "launch", "dispatch_wait", "u_fold",
                       "pairing"):
             assert {s[1] for s in rec.named(stage)} == {caller}, stage
+
+    def test_fused_sigma_roots_all_native(self, proved10, one_shape,
+                                          native_core):
+        """With the core built every σ root of a fused check comes from
+        it: sigma_native_lanes equals sigma_lanes."""
+        from cess_tpu.proof.xla_backend import proof_stage_registry
+
+        reg = proof_stage_registry()
+        before = stage_samples(reg)
+        assert XlaBackend(fused=True).verify_batch(
+            PK, proved10[:7], b"native", PARAMS) == [True] * 7
+        after = stage_samples(reg)
+        delta = {k: after[k] - before.get(k, 0.0) for k in after}
+        assert delta["cess_proof_sigma_lanes_total"] == 7
+        assert delta["cess_proof_sigma_native_lanes_total"] == 7
+        assert delta["cess_proof_stage_sigma_decode_seconds_count"] == 1
 
     def test_rejected_batch_observes_nothing(self, proved10, monkeypatch):
         from cess_tpu.proof.xla_backend import proof_stage_registry
