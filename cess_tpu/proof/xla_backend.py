@@ -77,7 +77,12 @@ _DEVICE_H2C_MIN_PAIRS = 256
 # dispatch of each chunk, and of the partial reduction) and
 # `dispatch_wait` (the block on device results after every chunk is in
 # flight).  The fused path also counts each launched chunk's live
-# proofs and padded slots.  docs/perf.md explains how to read the split.
+# proofs and padded slots.  Around the checks, `verify_batch` marks
+# the bisection tree a failing batch opens: `bisect` encloses each
+# combined check below the root (its own stages nest inside it) and
+# `leaf` each single-proof verify at a failing leaf, and counts both;
+# an all-honest batch, checked at the root alone, marks neither.
+# docs/perf.md explains how to read the split.
 #
 # Overhead guard: each stage costs two perf_counter calls, one span
 # object and one locked histogram observe — microseconds against
@@ -87,7 +92,7 @@ _DEVICE_H2C_MIN_PAIRS = 256
 
 STAGE_NAMES = ("front_end", "sigma_decode", "host_prep", "prep", "launch",
                "u_fold", "sigma_fold", "chunk_program", "dispatch_wait",
-               "pairing")
+               "pairing", "bisect", "leaf")
 
 PROOF_STAGES = StagePlane(
     "proof", STAGE_NAMES, "cess_proof_stage_{}_seconds",
@@ -107,6 +112,11 @@ PROOF_STAGES = StagePlane(
         "sigma_native_lanes": ("cess_proof_sigma_native_lanes_total",
                                "decoded sigma points whose square roots "
                                "came from the native core"),
+        "bisect_checks": ("cess_proof_bisect_checks_total",
+                          "combined checks below the root of the "
+                          "bisection tree"),
+        "leaf_checks": ("cess_proof_leaf_checks_total",
+                        "single-proof verifications at failing leaves"),
     })
 
 
@@ -427,14 +437,30 @@ class XlaBackend(ProofBackend):
         seed: bytes,
         params: Podr2Params,
     ) -> list[bool]:
+        stage = PROOF_STAGES.stage
+
+        def batch_check(pk_, subset, seed_, params_):
+            # only the root of the bisection tree holds every item; each
+            # other check re-checks a strictly smaller subset below it
+            if len(subset) == len(items):
+                return self._combined_check(pk_, subset, seed_, params_)
+            with stage("bisect"):
+                verdict = self._combined_check(pk_, subset, seed_, params_)
+            PROOF_STAGES.count(bisect_checks=1)
+            return verdict
+
         def single_check(pk_, item, params_):
             name, challenge, proof = item
-            return podr2.verify(pk_, name, challenge, proof, s=params_.s)
+            with stage("leaf"):
+                verdict = podr2.verify(pk_, name, challenge, proof,
+                                       s=params_.s)
+            PROOF_STAGES.count(leaf_checks=1)
+            return verdict
 
         self._h_memo = {}
         try:
             return self._verdicts_by_bisection(
-                pk, items, seed, params, self._combined_check, single_check
+                pk, items, seed, params, batch_check, single_check
             )
         finally:
             self._h_memo = {}

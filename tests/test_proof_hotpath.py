@@ -435,6 +435,8 @@ class TestStagedPathParity:
         fusedb = observed(XlaBackend(fused=True))
         assert {"front_end", "host_prep", "u_fold", "pairing"} <= (
             staged & fusedb)
+        # an all-honest batch is checked at the root alone
+        assert not {"bisect", "leaf"} & (staged | fusedb)
         assert "dispatch_wait" in fusedb and "launch" in fusedb
         assert "prep" in fusedb and "chunk_program" not in fusedb
         assert "chunk_program" in staged and "launch" not in staged
